@@ -12,10 +12,10 @@ are the adoption argument; on CPU the gather timing is the real serving
 number and the kernels run in interpret mode (bit-exact kernel-body
 execution — a correctness cost, not a TPU projection), so each row
 carries its execution `mode`. Structural numbers for the TPU target
-(VMEM per block, arithmetic intensity) are derived analytically; the
-fused backend is *skipped* — recorded as absent with
-`fused_fits_vmem: false` on the geometry's other rows — where its int8
-one-hot block cannot fit the 16 MiB VMEM at any useful tile, which is
+(VMEM per block) are derived analytically; the fused backend is
+*skipped* — recorded as absent with `fused_fits_vmem: false` on the
+geometry's other rows — where its int8 table block cannot fit the
+16 MiB VMEM at any useful tile, which is
 exactly the regime the packed kernel exists for (DESIGN §2 "Packed
 layout").
 
@@ -66,14 +66,11 @@ def zoo_geometries():
 
 
 def fused_vmem_kib(b: int, n: int, m: int, e: int) -> float:
-    bb, bf = fused_wnn.resolve_blocks(b, e)
-    return fused_wnn.block_vmem_bytes(bb, bf, n, m, e) / 1024.0
+    return fused_wnn.vmem_plan(b, n, m, e)["vmem_bytes"] / 1024.0
 
 
 def packed_vmem_kib(b: int, n: int, m: int, e: int) -> float:
-    w = packed_wnn.word_count(e)
-    bb, bf = packed_wnn.resolve_blocks(b, w)
-    return packed_wnn.block_vmem_bytes(bb, bf, n, m, w) / 1024.0
+    return packed_wnn.vmem_plan(b, n, m, e)["vmem_bytes"] / 1024.0
 
 
 def bench_geometry(model: str, sm_idx: int, n_f: int, n: int, e: int, *,
@@ -122,7 +119,7 @@ def bench_geometry(model: str, sm_idx: int, n_f: int, n: int, e: int, *,
                  "mode on CPU)")
     if not fits:
         emit(f"kernel.wnn.{model}.sm{sm_idx}.fused_skipped", "over-vmem",
-             f"int8 one-hot block {vmem['fused']:.0f} KiB > "
+             f"int8 table block {vmem['fused']:.0f} KiB > "
              f"{VMEM_LIMIT // 1024} KiB; packed block "
              f"{vmem['packed']:.0f} KiB")
     return rows
@@ -131,19 +128,16 @@ def bench_geometry(model: str, sm_idx: int, n_f: int, n: int, e: int, *,
 def structural_report() -> None:
     """Analytical TPU-target numbers for the kernels (no hardware)."""
     b, n_f, n, m, e, k = 256, 131, 12, 10, 64, 2   # ULN-S SM0-like
-    bb, bf = fused_wnn.resolve_blocks(b, e)
-    vmem = fused_wnn.block_vmem_bytes(bb, bf, n, m, e)
-    flops = 2 * bb * m * bf * e * k                # one-hot matmuls
-    emit("kernel.fused_wnn.vmem_kib_per_block", f"{vmem / 1024:.0f}",
-         f"block=({bb},{bf}) fits 16MiB VMEM: {vmem < VMEM_LIMIT}")
-    emit("kernel.fused_wnn.arith_intensity", f"{flops / max(1, vmem):.1f}",
-         "flops per VMEM byte; MXU-aligned dims (E=64, M pad 128)")
-    w = packed_wnn.word_count(e)
-    pbb, pbf = packed_wnn.resolve_blocks(b, w)
-    pvmem = packed_wnn.block_vmem_bytes(pbb, pbf, n, m, w)
-    emit("kernel.packed_wnn.vmem_kib_per_block", f"{pvmem / 1024:.0f}",
-         f"block=({pbb},{pbf}) W={w} words; one-hot 32x narrower, "
-         "table bytes 8x denser")
+    plan = fused_wnn.vmem_plan(b, n, m, e)
+    emit("kernel.fused_wnn.vmem_kib_per_block",
+         f"{plan['vmem_bytes'] / 1024:.0f}",
+         f"block=({plan['block_b']},{plan['block_f']}) fits 16MiB VMEM: "
+         f"{plan['fits']}")
+    pplan = packed_wnn.vmem_plan(b, n, m, e)
+    emit("kernel.packed_wnn.vmem_kib_per_block",
+         f"{pplan['vmem_bytes'] / 1024:.0f}",
+         f"block=({pplan['block_b']},{pplan['block_f']}) "
+         f"W={packed_wnn.word_count(e)} words; table bytes 8x denser")
     # the headline: largest submodel VMEM at the ULN-XL entry count
     e_xl = XL_GEOMS[0][4]
     emit("kernel.packed_wnn.uln_xl_vmem_kib",

@@ -188,7 +188,7 @@ def uleen_cell_program(shape: str, mesh, *,
                 ins["bits"])
         lower = lambda: uleen_cell.lower_uleen_infer_cell(
             mesh, global_batch=batch, spec=spec, backend=backend)
-        # the int8-table cell deploys the fused (one-hot MXU) kernel
+        # the int8-table cell deploys the fused (select-scan) kernel
         prog.kernel_geometries = kernel_geometries(spec, batch, "fused")
     elif shape == "infer_multitenant_scale":
         tenants = uleen_cell.MULTITENANT_TENANTS

@@ -322,12 +322,10 @@ def scores_from_prep(prep, bits: jnp.ndarray, *,
     for table, mask, perm, h3 in zip(prep.tables, prep.masks, prep.perms,
                                      prep.h3s):
         tuples = bits[:, perm].astype(jnp.int8)
-        # constrain every partial accumulation HERE, not inside the
-        # jit-cached wnn_scores (its trace must stay mesh-free)
-        scores = sh.logical_constraint(
-            scores + ops.wnn_scores(tuples, h3, table, mask, zero_bias,
-                                    backend=backend),
-            ("batch", "classes"))
+        # inside a mesh each partial is ("batch", "classes")-sharded
+        # by wnn_scores' shard_map
+        scores = scores + ops.wnn_scores(tuples, h3, table, mask, zero_bias,
+                                         backend=backend)
     # pin the bias add too: bias is class-sharded, and an unconstrained
     # `scores + bias` lets GSPMD hoist the gather above the add — two
     # all-gathers instead of the dataflow's promised one

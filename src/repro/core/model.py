@@ -209,7 +209,7 @@ def forward_binary_fused(spec: UleenSpec, statics: Sequence[SubmodelStatic],
     thermometer tuples — subsuming `compute_hashes` +
     `bloom.binary_filter_response` + mask/bias application. With
     `backend="fused"` each submodel is ONE Pallas kernel launch
-    (hash → one-hot MXU lookup → AND → popcount), the paper's whole
+    (hash → select-scan lookup → AND → popcount), the paper's whole
     accelerator pipeline; `"gather"` runs the jnp oracle on the same
     tuples and is bit-identical; `"packed"` runs the uint32 bitplane
     kernel (the int8 tables are packed at trace time — steady-state

@@ -56,41 +56,92 @@ def block_rng(rng: jax.Array, block: int | jnp.ndarray) -> jax.Array:
     return jax.random.fold_in(rng, block)
 
 
-def blocked_grads(loss_fn, params, hashes, labels, rng, grad_blocks: int):
-    """(grads, loss, acc) via the canonical blocked batch reduction.
+def make_loss_fn(spec: UleenSpec, smoothing: float = 0.0) -> Callable:
+    """(params, hashes, labels, rng) -> (loss, acc) of one batch: the STE
+    forward with dropout, cross-entropy and accuracy."""
+    def loss_fn(params: UleenParams, hashes, labels, rng):
+        scores = forward(spec, params, hashes, train=True, rng=rng)
+        loss = cross_entropy(scores, labels, smoothing)
+        acc = jnp.mean(jnp.argmax(scores, -1) == labels)
+        return loss, acc
+    return loss_fn
 
-    The batch splits into `grad_blocks` equal row blocks; each block's
-    gradient is computed whole (its own dropout rng via `block_rng`), and
-    the blocks combine by a left fold in block order (lax.scan), divided
-    by the block count at the end. The fold order is FIXED — independent
-    of how the batch is later laid out over a mesh — so a distributed
-    trainer that computes the same blocks on different devices and folds
-    the gathered stack reproduces this function bit-for-bit (DESIGN §10:
-    float addition is not associative; a plain `jnp.mean` over a sharded
-    batch is reduced in mesh-dependent order and drifts ~1e-7/step).
-    """
-    s = grad_blocks
+
+def block_grads(loss_fn, params, hashes, labels, rng, blocks: int,
+                first_block: int | jnp.ndarray = 0):
+    """Per-block (grads, loss, acc) stacks, each with leading axis
+    `blocks`: the rows split into `blocks` equal blocks, block j of them
+    global block `first_block + j`, each differentiated whole with its own
+    dropout rng (`block_rng`). The one per-block computation of both
+    trainers (DESIGN §10)."""
     b = labels.shape[0]
-    if b % s:
-        raise ValueError(f"batch {b} not divisible by grad_blocks {s}")
-    hs = tuple(h.reshape(s, b // s, *h.shape[1:]) for h in hashes)
-    ys = labels.reshape(s, b // s)
-    rngs = jax.vmap(lambda i: block_rng(rng, i))(jnp.arange(s))
+    if b % blocks:
+        raise ValueError(f"batch {b} not divisible by {blocks} blocks")
+    hs = tuple(h.reshape(blocks, b // blocks, *h.shape[1:]) for h in hashes)
+    ys = labels.reshape(blocks, b // blocks)
+
+    def block(j):
+        (loss, acc), g = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, tuple(h[j] for h in hs), ys[j],
+            block_rng(rng, first_block + j))
+        return g, loss, acc
+
+    return jax.lax.map(block, jnp.arange(blocks))
+
+
+def fold_blocks(grads, losses, accs):
+    """(grads, loss, acc) from per-block stacks with leading axis S: a left
+    fold in block order (lax.scan), divided by S at the end.
+
+    The one reduction both trainers run (DESIGN §10). The fold is its own
+    loop over already-materialised block gradients: accumulating inside
+    the loop that computes them lets XLA merge the running sum into each
+    block's scatter-add, which changes the summation order and drifts the
+    table gradients by an ulp.
+    """
+    s = losses.shape[0]
 
     def body(acc, xs):
         g_acc, l_acc, a_acc = acc
-        hb, yb, rb = xs
-        (loss, bacc), g = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, hb, yb, rb)
-        g_acc = jax.tree.map(lambda x, y: x + y, g_acc, g)
-        return (g_acc, l_acc + loss, a_acc + bacc), None
+        gb, lb, ab = xs
+        return (jax.tree.map(lambda x, y: x + y, g_acc, gb),
+                l_acc + lb, a_acc + ab), None
 
-    zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    (grads, loss, acc), _ = jax.lax.scan(
+    zeros = jax.tree.map(lambda g: jnp.zeros(g.shape[1:], jnp.float32),
+                         grads)
+    (g, loss, acc), _ = jax.lax.scan(
         body, (zeros, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (hs, ys, rngs))
+        (grads, losses, accs))
     inv = 1.0 / s
-    return jax.tree.map(lambda g: g * inv, grads), loss * inv, acc * inv
+    return jax.tree.map(lambda x: x * inv, g), loss * inv, acc * inv
+
+
+def blocked_grads(loss_fn, params, hashes, labels, rng, grad_blocks: int):
+    """(grads, loss, acc) via the canonical blocked batch reduction.
+
+    The batch splits into `grad_blocks` equal row blocks (`block_grads`),
+    and the stacked block gradients combine by `fold_blocks`. The fold
+    order is FIXED — independent of how the batch is later laid out over
+    a mesh — so a distributed trainer that computes the same blocks on
+    different devices and folds the gathered stack reproduces this
+    function bit-for-bit (DESIGN §10: float addition is not associative;
+    a plain `jnp.mean` over a sharded batch is reduced in mesh-dependent
+    order and drifts ~1e-7/step).
+    """
+    return fold_blocks(*block_grads(loss_fn, params, hashes, labels, rng,
+                                    grad_blocks))
+
+
+def apply_step(optimizer: opt_lib.Optimizer, params, opt_state, grads,
+               clip_table: float = 1.0):
+    """The optimizer update both trainers apply: Adam, then the table
+    clip to [-clip_table, clip_table]."""
+    updates, opt_state = optimizer.update(grads, opt_state, params)
+    params = opt_lib.apply_updates(params, updates)
+    if clip_table:
+        params = params._replace(tables=tuple(
+            jnp.clip(t, -clip_table, clip_table) for t in params.tables))
+    return params, opt_state
 
 
 def make_train_step(spec: UleenSpec, optimizer: opt_lib.Optimizer,
@@ -103,11 +154,7 @@ def make_train_step(spec: UleenSpec, optimizer: opt_lib.Optimizer,
     reduction (`blocked_grads`) — the parity reference the executed
     distributed trainer is asserted bit-identical against (DESIGN §10).
     """
-    def loss_fn(params: UleenParams, hashes, labels, rng):
-        scores = forward(spec, params, hashes, train=True, rng=rng)
-        loss = cross_entropy(scores, labels, smoothing)
-        acc = jnp.mean(jnp.argmax(scores, -1) == labels)
-        return loss, acc
+    loss_fn = make_loss_fn(spec, smoothing)
 
     def train_step(params, opt_state, hashes, labels, rng):
         if grad_blocks > 1:
@@ -116,11 +163,8 @@ def make_train_step(spec: UleenSpec, optimizer: opt_lib.Optimizer,
         else:
             (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, hashes, labels, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = opt_lib.apply_updates(params, updates)
-        if clip_table:
-            params = params._replace(tables=tuple(
-                jnp.clip(t, -clip_table, clip_table) for t in params.tables))
+        params, opt_state = apply_step(optimizer, params, opt_state, grads,
+                                       clip_table)
         return params, opt_state, loss, acc
 
     return train_step

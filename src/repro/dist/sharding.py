@@ -183,26 +183,16 @@ def strip_axis(rules: ShardingRules, axis: str) -> ShardingRules:
 
 
 def shard_map(f, mesh, *, in_specs, out_specs, manual_axes=None):
-    """Version-portable `shard_map` (the executed-trainer entry, DESIGN §10).
-
-    jax renamed this API twice (jax.experimental.shard_map.shard_map with
-    `check_rep`/`auto` -> jax.shard_map with `check_vma`/`axis_names`).
-    Every manual-collective region in the repo goes through this wrapper so
-    the executed distributed trainer runs on whichever jax the container
-    ships. `manual_axes`: the mesh axes the region is manual over (default
-    all of them); replication checking is disabled — our manual regions
-    return deliberately-replicated outputs the checker cannot verify.
-    """
+    """`jax.shard_map` manual over `manual_axes` (default: every mesh
+    axis), the entry of every manual-collective region in the repo
+    (DESIGN §10). Replication checking is off: these regions return
+    deliberately-replicated outputs the checker cannot verify."""
+    import jax
     manual = frozenset(manual_axes if manual_axes is not None
                        else mesh.axis_names)
-    top = getattr(__import__("jax"), "shard_map", None)
-    if top is not None:                      # jax >= 0.6 style
-        return top(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   axis_names=manual, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - manual
-    return _sm(f, mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -2,9 +2,9 @@
 
 The multi-shot trainer hashes the full training set once per run; for MNIST-
 scale data that is B x N_f x k hashes over n-bit tuples. The kernel is the
-same unrolled XOR-select reduction the fused inference kernel uses, tiled
-(batch x filters) so each block's tuples live in VMEM while the (k, n)
-parameter matrix stays resident (the paper's shared "Param RF").
+unrolled XOR-select reduction of H3, tiled (batch x filters) so each
+block's tuples live in VMEM while the (k, n) parameter matrix stays
+resident (the paper's shared "Param RF").
 """
 from __future__ import annotations
 
@@ -14,7 +14,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.fused_wnn import _h3_hashes
+
+def _h3_hashes(bits_i32: jnp.ndarray, params_row) -> jnp.ndarray:
+    """bits: (..., n) int32 in {0,1}; params_row: (n,) int32 -> (...,) int32.
+
+    XOR-reduce of parameter words selected by set bits (unrolled; n <= ~40).
+    """
+    n = bits_i32.shape[-1]
+    acc = jnp.zeros(bits_i32.shape[:-1], jnp.int32)
+    for i in range(n):
+        acc = acc ^ jnp.where(bits_i32[..., i] != 0, params_row[i], 0)
+    return acc
 
 
 def h3_hash_kernel(tuples_ref, params_ref, out_ref, *, num_hashes: int):

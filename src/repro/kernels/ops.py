@@ -11,6 +11,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as sh
 from repro.kernels import ref
@@ -66,7 +67,7 @@ def validate_wnn_geometry(tuples, params, table, mask, bias, *,
     cannot honour bit-exactly — most importantly non-power-of-two
     `entries`: H3 XOR-composes parameter words in [0, E), which stays
     in-range only when E is a power of two; out-of-range hashes would
-    one-hot to zero in the fused kernel but clip in the gather's
+    match no entry (read zero) in the fused kernel but clip in the gather's
     `take_along_axis` (and address the wrong word in the packed layout).
     """
     if tuples.ndim != 3:
@@ -107,7 +108,6 @@ def validate_wnn_geometry(tuples, params, table, mask, bias, *,
         raise ValueError(f"k={k} outside [1, {_MAX_HASHES}]")
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "entries"))
 def wnn_scores(tuples, params, table, mask, bias, *, backend: str = "auto",
                entries: int | None = None):
     """One submodel's inference scores (B, M) int32, backend-dispatched.
@@ -117,12 +117,12 @@ def wnn_scores(tuples, params, table, mask, bias, *, backend: str = "auto",
     (dtype-dispatched; packed input requires the static `entries=`);
     mask: (M, N_f) int8; bias: (M,) int32.
 
-    backend="fused"  — the one-hot MXU Pallas kernel on int8 tables
+    backend="fused"  — the select-scan Pallas kernel on int8 tables
                        (interpret mode off-TPU, so the exact TPU kernel
                        body runs bit-for-bit on CPU);
     backend="gather" — the jnp take_along_axis oracle (`ref.fused_wnn_ref`);
     backend="packed" — the bitplane Pallas kernel (`packed_wnn`): word
-                       gather via one-hot over E/32 uint32 words +
+                       select scan over E/32 uint32 words +
                        shift/AND bit extract; interpret mode off-TPU.
                        int8 tables are packed at trace time (a tests/
                        bench convenience — serving packs once, see
@@ -136,15 +136,34 @@ def wnn_scores(tuples, params, table, mask, bias, *, backend: str = "auto",
     (tests/test_fused_adoption.py + tests/test_packed.py enforce int32
     equality).
 
-    Under class-partitioned tables (DESIGN §7) each device computes only
-    its own class columns — the per-shard partial scores of the sharded
-    serve path. The ("batch", "classes") constraints steering GSPMD live
-    in the (uncached) accumulators `packed.packed_scores` /
-    `export.scores_from_prep`, NOT here: this function is an inner
-    `jax.jit` whose trace cache is keyed on avals only, so it must never
-    capture the thread-local `use_mesh` context (a trace pinned to one
-    mesh's devices would be replayed on the next mesh).
+    Inside a `use_mesh` context the submodel runs per shard under
+    `shard_map` — rows over the batch axes, classes over theirs — since
+    GSPMD cannot partition a Mosaic kernel; each shard scores its own
+    class columns (DESIGN §7) and there is no collective. This is the one
+    way every caller reaches the kernels, so no caller can miss it. The
+    mesh decision is made here, outside the jitted `_local_scores`, whose
+    trace cache is keyed on avals only and so must never capture the
+    thread-local `use_mesh` context.
     """
+    score = functools.partial(_local_scores, backend=backend,
+                              entries=entries)
+    ctx = sh.current_context()
+    if ctx is None:
+        return score(tuples, params, table, mask, bias)
+    mesh, rules = ctx
+    rows, classes = rules.resolve(("batch", "classes"), mesh,
+                                  shape=(tuples.shape[0], table.shape[0]))
+    return sh.shard_map(
+        score, mesh,
+        in_specs=(P(rows, None, None), P(), P(classes, None, None),
+                  P(classes, None), P(classes)),
+        out_specs=P(rows, classes))(tuples, params, table, mask, bias)
+
+
+@functools.partial(jax.jit, static_argnames=("backend", "entries"))
+def _local_scores(tuples, params, table, mask, bias, *, backend: str,
+                  entries: int | None):
+    """`wnn_scores` on the arrays as given, with no mesh."""
     packed_in = table.dtype == jnp.uint32
     validate_wnn_geometry(tuples, params, table, mask, bias, entries=entries)
     resolved = resolve_wnn_backend(backend, packed_tables=packed_in)
@@ -248,7 +267,6 @@ def ensemble_predict(scores):
     return scores, jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def wnn_infer(tuples, params, table, mask, bias, *, use_kernel: bool = False):
     """Fused WNN inference scores (B, M) int32 (one submodel).
 

@@ -1,150 +1,69 @@
 """Packed-domain ULEEN inference kernel: bitplane tables, never unpacked.
 
-The fused kernel (`fused_wnn.py`) holds `(M, N_f, E)` int8 tables in VMEM —
-8 bits per Bloom-filter entry where the accelerator stores 1, and a
-`(Bt, Ft, E)` one-hot that dominates the block's VMEM at large E. This
-kernel keeps the tables in the artifact's native uint32 bitplane layout
+The fused kernel (`fused_wnn.py`) scans `(M, N_f, E)` int8 tables — 8 bits
+per Bloom-filter entry where the accelerator stores 1. This kernel keeps
+the tables in the artifact's native uint32 bitplane layout
 (`core/export.py::pack_table`: 32 entries per word, little-endian bits):
 
     entry h of filter (m, f)  ==  bit (h & 31) of word[m, f, h >> 5]
 
-Per probe it gathers the `(hash >> 5)` word — as a one-hot MXU contraction
-over W = E/32 words, the same systolic trick as the fused kernel but 32×
-narrower — then extracts the addressed bit with shift/AND on the VPU. The
-AND-across-k (product), popcount (block partial sum) and bias epilogue are
-identical to `fused_wnn_kernel`, so the two kernels are exactly
-score-equal by contract.
+It runs the fused kernel's body (`fused_wnn.wnn_lookup`) over the W = E/32
+words instead of the E entries: the select scan picks word `h >> 5`, and
+shift/AND on the VPU extracts the addressed bit. Hash, AND-across-k,
+popcount and bias are shared, so the two kernels are exactly score-equal
+by contract.
 
-VMEM per block: one-hot (Bt, Ft, W) int32 + table (M, Ft, W) int32 =
-(Bt + M) · Ft · E/8 bytes, vs (Bt + M) · Ft · E for the int8 kernel — an
-8× byte density win that lets blocks hold ~32× more entries per one-hot
-lane, unblocking ULN-XL geometries (E ≥ 2^13) whose int8 one-hot alone
-overflows the 16 MiB VMEM (DESIGN §2 "Packed layout").
+VMEM per class and filter tile is W·4 bytes of block and W·4 of
+transposed scratch against E bytes and E·4 for int8 — an 8× density win
+or better, and the scan is 32× shorter. At ULN-XL (E = 2^15) the
+int8 block cannot hold the classes at all, while the packed plan splits
+them into class blocks that fit (DESIGN §2 "Packed layout").
 
 The uint32 words are bitcast to int32 outside the kernel (bit pattern
-preserved); the one-selected-word contraction is exact in int32, and
-`(word >> b) & 1` extracts bit b correctly under arithmetic shift.
+preserved); `(word >> b) & 1` extracts bit b correctly under arithmetic
+shift.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.fused_wnn import VMEM_LIMIT, _h3_hashes
+from repro.kernels.fused_wnn import VMEM_LIMIT  # noqa: F401  (re-export)
+from repro.kernels.fused_wnn import plan_blocks, wnn_lookup
 # the single definition of the packed word-width rule (one whole padded
 # word for E < 32) — validation (ops.py) and kernel blocking must agree
-from repro.packed.layout import word_count  # noqa: F401 (re-exported)
-
-VMEM_BUDGET = 4 * 1024 * 1024
+from repro.packed.layout import word_count
 
 
-def resolve_blocks(b: int, words: int, *, block_b: int = 128,
-                   block_f: int = 512) -> tuple[int, int]:
-    """(block_b, block_f) after the VMEM budget clamp: the one-hot is
-    (Bt, Ft, W) int32, so Ft scales inversely with W·4 bytes."""
-    block_b = min(block_b, max(8, b))
-    block_f = min(block_f,
-                  max(8, VMEM_BUDGET // max(1, block_b * words * 4)))
-    return block_b, block_f
-
-
-def block_vmem_bytes(block_b: int, block_f: int, n: int, m: int,
-                     words: int) -> int:
-    """Analytical VMEM footprint of one block (bench + DESIGN arithmetic)."""
-    return (block_b * block_f * n            # tuples int8
-            + m * block_f * words * 4        # packed table int32
-            + block_b * block_f * words * 4  # word one-hot int32
-            + block_b * m * 4)               # accumulator int32
+def word_plan(b: int, n: int, m: int, words: int, *,
+              block_b: int = 128) -> dict:
+    """The block geometry `packed_wnn` launches for B = b rows over W =
+    `words` words per filter: classes split into blocks where the whole
+    class set's words would not fit."""
+    return plan_blocks(b, n, m, words, 4, block_b=block_b,
+                       split_classes=True)
 
 
 def vmem_plan(b: int, n: int, m: int, entries: int, *,
-              block_b: int = 128, block_f: int = 512) -> dict:
-    """The block geometry `packed_wnn` would launch for (b, n, m, entries)
-    and whether its analytical VMEM footprint fits the hard per-core
-    limit (`fused_wnn.VMEM_LIMIT`) — the packed twin of
-    `fused_wnn.vmem_plan`, taking E and deriving W = word_count(E)."""
-    w = word_count(entries)
-    bb, bf = resolve_blocks(b, w, block_b=block_b, block_f=block_f)
-    vmem = block_vmem_bytes(bb, bf, n, m, w)
-    return {"block_b": bb, "block_f": bf, "vmem_bytes": vmem,
-            "fits": vmem <= VMEM_LIMIT}
-
-
-def packed_wnn_kernel(tuples_ref, params_ref, words_ref, mask_ref, bias_ref,
-                      out_ref, *, num_words: int, num_hashes: int):
-    f_idx = pl.program_id(1)
-    bits = tuples_ref[...].astype(jnp.int32)          # (Bt, Ft, n)
-    words = words_ref[...]                            # (M, Ft, W) int32 planes
-    # Canonical mask semantics (core/bloom.py::apply_mask): survive iff
-    # nonzero — magnitude never scales the response.
-    mask = (mask_ref[...] != 0).astype(jnp.int32)     # (M, Ft)
-    bt, ft, _ = bits.shape
-    m = words.shape[0]
-
-    resp = jnp.ones((bt, m, ft), jnp.int32)
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (bt, ft, num_words), 2)
-    for j in range(num_hashes):
-        h = _h3_hashes(bits, params_ref[j, :])        # (Bt, Ft) in [0, E)
-        onehot = (iota_w == (h[..., None] >> 5)).astype(jnp.int32)
-        # (Bt, Ft, W) x (M, Ft, W) -> (Ft, Bt, M): the word gather as a
-        # batched contraction — exactly one word survives per (b, f).
-        word = jax.lax.dot_general(
-            onehot, words,
-            dimension_numbers=(((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.int32)
-        word = jnp.transpose(word, (1, 2, 0))         # (Bt, M, Ft)
-        bit = (word >> (h & 31)[:, None, :]) & 1      # shift/AND extract
-        resp = resp * bit                             # AND across hashes
-    resp = resp * mask[None]                          # (Bt, M, Ft)
-    partial = jnp.sum(resp, axis=-1)                  # (Bt, M)
-
-    @pl.when(f_idx == 0)
-    def _init():
-        out_ref[...] = partial + bias_ref[...][None, :]
-
-    @pl.when(f_idx != 0)
-    def _acc():
-        out_ref[...] += partial
+              block_b: int = 128) -> dict:
+    """`word_plan` for E = `entries` (W = word_count(E)), and whether its
+    analytical VMEM footprint fits the hard per-core limit
+    (`VMEM_LIMIT`) — the packed twin of `fused_wnn.vmem_plan`, evaluated
+    without tracing for the lint layer."""
+    return word_plan(b, n, m, word_count(entries), block_b=block_b)
 
 
 def packed_wnn(tuples: jnp.ndarray, params: jnp.ndarray,
                words: jnp.ndarray, mask: jnp.ndarray, bias: jnp.ndarray, *,
-               block_b: int = 128, block_f: int = 512,
-               interpret: bool = False) -> jnp.ndarray:
+               block_b: int = 128, interpret: bool = False) -> jnp.ndarray:
     """tuples: (B, N_f, n) int8 {0,1}; params: (k, n) int32;
     words: (M, N_f, W) uint32 bitplanes; mask: (M, N_f) int8;
     bias: (M,) int32 -> scores (B, M) int32. Pads B and N_f internally;
-    padded filters carry zero words + zero mask, so they never score.
+    padded filters carry zero mask, so they never score.
     """
-    b, n_f, n = tuples.shape
+    b, _, n = tuples.shape
     m, _, w = words.shape
-    k = params.shape[0]
-    block_b, block_f = resolve_blocks(b, w, block_b=block_b,
-                                      block_f=block_f)
-    pb, pf = (-b) % block_b, (-n_f) % block_f
-    if pb or pf:
-        tuples = jnp.pad(tuples, ((0, pb), (0, pf), (0, 0)))
-        words = jnp.pad(words, ((0, 0), (0, pf), (0, 0)))
-        mask = jnp.pad(mask, ((0, 0), (0, pf)))
-    bp, fp = tuples.shape[0], tuples.shape[1]
+    plan = word_plan(b, n, m, w, block_b=block_b)
     words_i32 = jax.lax.bitcast_convert_type(words, jnp.int32)
-
-    kernel = functools.partial(packed_wnn_kernel, num_words=w, num_hashes=k)
-    out = pl.pallas_call(
-        kernel,
-        grid=(bp // block_b, fp // block_f),
-        in_specs=[
-            pl.BlockSpec((block_b, block_f, n), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((k, n), lambda i, j: (0, 0)),
-            pl.BlockSpec((m, block_f, w), lambda i, j: (0, j, 0)),
-            pl.BlockSpec((m, block_f), lambda i, j: (0, j)),
-            pl.BlockSpec((m,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_b, m), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bp, m), jnp.int32),
-        interpret=interpret,
-    )(tuples, params, words_i32, mask, bias)
-    return out[:b]
+    return wnn_lookup(tuples, params, words_i32, mask, bias, plan,
+                      packed=True, interpret=interpret)

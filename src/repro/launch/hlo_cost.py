@@ -150,10 +150,6 @@ def roofline_from(compiled_text: str, cost: dict, chips: int,
     counts while bodies once, so the headline terms come from the expanded
     walk; the raw XLA numbers are kept for reference."""
     from repro.launch import hlo_analysis
-    # Compiled.cost_analysis() returns [dict] (one per program) on some jax
-    # versions and a bare dict on others.
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     mod = hlo_analysis.analyze(compiled_text)
 
     flops = mod.dot_flops

@@ -12,13 +12,10 @@ else in the rule set changes (DESIGN §4).
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import numpy as np
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    import jax
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return make_mesh(shape, axes)
@@ -28,6 +25,7 @@ def make_mesh(shape: tuple, axes: tuple):
     """Mesh over the first prod(shape) devices (placeholder CPU devices in
     the dry-run; real TPU topology on a fleet)."""
     import jax
+    from jax.sharding import AxisType
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) < n:
@@ -36,11 +34,11 @@ def make_mesh(shape: tuple, axes: tuple):
             f"{len(devices)} — the dry-run entry point must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import")
-    try:
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:  # older jax: no devices kwarg
-        from jax.sharding import Mesh
-        return Mesh(np.array(devices[:n]).reshape(shape), axes)
+    # Auto axes: every sharding in the repo is steered by GSPMD
+    # (`with_sharding_constraint`, jit in/out shardings), which
+    # `jax.make_mesh`'s default Explicit axes reject.
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(axes: tuple = ("data", "model")):

@@ -229,12 +229,15 @@ class Engine:
 
         with sh.use_mesh(self.mesh, self.rules):
             if self.paged:
-                self.state = steps.paged_serve_state_zeros(
+                state = steps.paged_serve_state_zeros(
                     cfg, params, slots, max_len,
                     block_size=self.block_size, num_blocks=self.num_blocks)
             else:
-                self.state = steps.serve_state_zeros(cfg, params, slots,
-                                                     max_len)
+                state = steps.serve_state_zeros(cfg, params, slots, max_len)
+        # placed on the engine mesh like every step's output state: a
+        # single-device input would key a second trace of each step
+        self.state = jax.device_put(
+            state, sh.named_sharding(self.mesh, self.rules, ()))
         self.slots = [_Slot() for _ in range(slots)]
         self.queue: collections.deque = collections.deque()
         self._next_tok = np.zeros((slots,), np.int32)

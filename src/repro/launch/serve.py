@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.configs.base import ARCH_IDS, get_config
 from repro.dist import sharding as sh
 from repro.launch import steps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer
 from repro.obs import jaxhooks as obs_jaxhooks
@@ -145,6 +146,7 @@ def main(argv=None) -> int:
                          "the run (latency histograms, retrace counters, "
                          "prefill/decode spans) to PATH")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     # independent streams: the same key must never both initialise params

@@ -145,11 +145,6 @@ def make_train_step(cfg: ArchConfig, optimizer: opt_lib.Optimizer, *,
                 return (grads, jax.lax.pmean(loss, "pod"),
                         jax.lax.pmean(aux, "pod"))
 
-            # shd.shard_map, not jax.shard_map: this jax predates the
-            # top-level alias, and the old experimental API spells the
-            # manual-axes/replication kwargs differently. The wrapper
-            # resolves both (found when this path first *executed* —
-            # lowering with cross_pod_mesh=None never reached it).
             grads, loss, aux = shd.shard_map(
                 per_pod, cross_pod_mesh, in_specs=P("pod"),
                 out_specs=P(), manual_axes=("pod",))(batch)

@@ -35,6 +35,7 @@ from repro.configs.base import ARCH_IDS, get_config
 from repro.data.synth import make_lm_tokens
 from repro.dist import sharding as sh
 from repro.launch import specs, steps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (make_host_mesh, make_mesh,
                                make_production_mesh)
 from repro.models import transformer
@@ -286,6 +287,39 @@ def train_uleen(spec, statics, bits_train, labels_train, *,
             "straggler_events": len(monitor.events)}
 
 
+def uleen_reference_params(spec, statics, bits_train, labels_train, *,
+                           steps: int, global_batch: int = 256,
+                           lr: float = 1e-3, grad_blocks: int = 8,
+                           seed: int = 0):
+    """Params of the single-device blocked reference
+    (`multi_shot.make_train_step(grad_blocks=)`) after `steps` steps of the
+    same (seed, step) schedule `train_uleen` runs — the oracle the
+    distributed trainer is bit-exact against (DESIGN §10)."""
+    from repro.core import multi_shot
+    from repro.core.model import compute_hashes, init_params
+
+    optimizer = opt_lib.adam(lr)
+    params = init_params(jax.random.PRNGKey(seed), spec, init_scale=0.1)
+    opt_state = optimizer.init(params)
+    ref_step = jax.jit(multi_shot.make_train_step(
+        spec, optimizer, grad_blocks=grad_blocks))
+    base = jax.random.PRNGKey(seed)
+    for s in range(steps):
+        idx = uleen_batch_indices(seed, s, bits_train.shape[0], global_batch)
+        h = compute_hashes(spec, statics, jnp.asarray(bits_train[idx]))
+        params, opt_state, _, _ = ref_step(
+            params, opt_state, h, jnp.asarray(labels_train[idx]),
+            jax.random.fold_in(base, s))
+    return params
+
+
+def max_param_diff(a, b) -> float:
+    """Max |Δ| over two param pytrees, compared on the host: the operands
+    may live on different meshes, which jnp refuses to mix."""
+    return max(float(np.max(np.abs(np.asarray(x) - np.asarray(y))))
+               for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
 def uleen_parity_probe(mesh, *, steps: int = 2, global_batch: int = 256,
                        grad_blocks: int = 8, seed: int = 0,
                        n_train: int = 1024) -> float:
@@ -294,28 +328,14 @@ def uleen_parity_probe(mesh, *, steps: int = 2, global_batch: int = 256,
     steps. 0.0 means bit-exact — the dryrun train_host_exec cell gates on
     exactly that (tests/test_distributed_training.py asserts it per-step
     over 10 steps; this is the same check sized for a smoke)."""
-    from repro.core import multi_shot
-    from repro.core.model import compute_hashes, init_params
-
     spec, statics, bits, labels = uleen_smoke_problem(seed, n_train=n_train)
     out = train_uleen(spec, statics, bits, labels, steps_total=steps,
                       global_batch=global_batch, grad_blocks=grad_blocks,
                       seed=seed, mesh=mesh, verbose=False)
-
-    optimizer = opt_lib.adam(1e-3)
-    params = init_params(jax.random.PRNGKey(seed), spec, init_scale=0.1)
-    opt_state = optimizer.init(params)
-    ref_step = jax.jit(multi_shot.make_train_step(
-        spec, optimizer, grad_blocks=grad_blocks))
-    base = jax.random.PRNGKey(seed)
-    for s in range(steps):
-        idx = uleen_batch_indices(seed, s, bits.shape[0], global_batch)
-        h = compute_hashes(spec, statics, jnp.asarray(bits[idx]))
-        params, opt_state, _, _ = ref_step(
-            params, opt_state, h, jnp.asarray(labels[idx]),
-            jax.random.fold_in(base, s))
-    return max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
-        jax.tree.leaves(out["params"]), jax.tree.leaves(params)))
+    ref = uleen_reference_params(spec, statics, bits, labels, steps=steps,
+                                 global_batch=global_batch,
+                                 grad_blocks=grad_blocks, seed=seed)
+    return max_param_diff(out["params"], ref)
 
 
 def parse_mesh(text: str):
@@ -383,6 +403,7 @@ def main(argv=None) -> int:
                          "the run (step-time histogram, checkpoint spans, "
                          "straggler EWMA) to PATH")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     def _run() -> int:
         if args.arch == "uleen":

@@ -48,7 +48,7 @@ ULEEN_EXEC_SPEC = UleenSpec(
 EXEC_BATCH = 256           # global batch of the executed host-mesh cell
 
 # ULN-XL: an ensemble past the int8 kernel's VMEM blocking — E up to 2^15
-# means the fused one-hot alone (block_b × block_f × E int8) overflows the
+# means the fused int8 table block (M × E × 128 filters) overflows the
 # 16 MiB VMEM at any useful block, while the packed bitplane kernel holds
 # the same tables in E/8 bytes per filter and blocks comfortably
 # (DESIGN §2 "Packed layout"). 784 px × 8 thermometer bits.
@@ -151,11 +151,7 @@ def make_uleen_dist_train_step(spec: UleenSpec, optimizer: opt_lib.Optimizer,
     if compress and "pod" not in sizes:
         raise ValueError("compress=True needs a `pod` mesh axis")
 
-    def loss_fn(p, hashes, labels, rng):
-        scores = uleen.forward(spec, p, hashes, train=True, rng=rng)
-        loss = cross_entropy(scores, labels, smoothing)
-        acc = jnp.mean(jnp.argmax(scores, -1) == labels)
-        return loss, acc
+    loss_fn = multi_shot.make_loss_fn(spec, smoothing)
 
     def local(params, statics_t, bits_l, labels_l, rng):
         sts = [uleen.SubmodelStatic(*st) for st in statics_t]
@@ -167,18 +163,9 @@ def make_uleen_dist_train_step(spec: UleenSpec, optimizer: opt_lib.Optimizer,
         dev = jnp.int32(0)
         for a in axes:
             dev = dev * sizes[a] + jax.lax.axis_index(a)
-        rows = bits_l.shape[0] // bpd
-        bs = bits_l.reshape(bpd, rows, bits_l.shape[1])
-        ys = labels_l.reshape(bpd, rows)
-
-        def block(j):
-            rb = multi_shot.block_rng(rng, dev * bpd + j)
-            h = uleen.compute_hashes(spec, sts, bs[j])
-            (l, a), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, h, ys[j], rb)
-            return g, l, a
-
-        gs, ls, accs = jax.lax.map(block, jnp.arange(bpd))
+        hashes = uleen.compute_hashes(spec, sts, bits_l)
+        gs, ls, accs = multi_shot.block_grads(
+            loss_fn, params, hashes, labels_l, rng, bpd, dev * bpd)
 
         if compress:
             # fp32 intra-pod (ICI), int8 cross-pod (the scarce link).
@@ -198,20 +185,7 @@ def make_uleen_dist_train_step(spec: UleenSpec, optimizer: opt_lib.Optimizer,
             gs)
         lall = jax.lax.all_gather(ls, axes).reshape(s)
         aall = jax.lax.all_gather(accs, axes).reshape(s)
-
-        def body(acc_c, xs):
-            g_acc, l_acc, a_acc = acc_c
-            gb, lb, ab = xs
-            return (jax.tree.map(lambda x, y: x + y, g_acc, gb),
-                    l_acc + lb, a_acc + ab), None
-
-        zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
-                             params)
-        (g, l, a), _ = jax.lax.scan(
-            body, (zeros, jnp.zeros((), jnp.float32),
-                   jnp.zeros((), jnp.float32)), (gall, lall, aall))
-        inv = 1.0 / s
-        return (jax.tree.map(lambda x: x * inv, g), l * inv, a * inv)
+        return multi_shot.fold_blocks(gall, lall, aall)
 
     bspec = P(axes if len(axes) > 1 else axes[0])
     grads_fn = sh.shard_map(
@@ -222,11 +196,9 @@ def make_uleen_dist_train_step(spec: UleenSpec, optimizer: opt_lib.Optimizer,
     def train_step(params, opt_state, statics, bits, labels, rng):
         statics_t = tuple(tuple(st) for st in statics)
         grads, loss, acc = grads_fn(params, statics_t, bits, labels, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = opt_lib.apply_updates(params, updates)
-        if clip_table:
-            params = params._replace(tables=tuple(
-                jnp.clip(t, -clip_table, clip_table) for t in params.tables))
+        params, opt_state = multi_shot.apply_step(optimizer, params,
+                                                  opt_state, grads,
+                                                  clip_table)
         return params, opt_state, loss, acc
 
     return train_step

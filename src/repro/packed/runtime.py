@@ -8,12 +8,13 @@ DESIGN §2 "Packed layout"). `core/export.py::artifact_scores` and the
 serve engine's WNN batch path (`launch/scheduler.py::WnnBatcher`) both
 route through here.
 
-Under an active `dist.sharding.use_mesh` context the score matrix is
-constrained to the ("batch", "classes") logical sharding, so tables
-partitioned over `model` by class (DESIGN §7) score their own class
-columns locally; `packed_predict` gathers the (B, M) matrix and takes the
-final argmax — the one cross-device step of the class-sharded dataflow.
-Outside a mesh context every constraint is a no-op.
+Under an active `dist.sharding.use_mesh` context each submodel's kernel
+runs per shard (`ops.wnn_scores`) and the score matrix keeps the
+("batch", "classes") logical sharding, so tables partitioned over `model`
+by class (DESIGN §7) score their own class columns locally;
+`packed_predict` gathers the (B, M) matrix and takes the final argmax —
+the one cross-device step of the class-sharded dataflow. Outside a mesh
+context every constraint is a no-op.
 """
 from __future__ import annotations
 
@@ -52,12 +53,10 @@ def packed_scores(pt: PackedTables, bits: jnp.ndarray, *,
     for words, mask, perm, h3, entries in zip(
             pt.words, pt.masks, pt.perms, pt.h3s, pt.entries):
         tuples = bits[:, perm].astype(jnp.int8)          # (B, N_f, n)
-        # constrain every partial accumulation HERE, not inside the
-        # jit-cached wnn_scores (its trace must stay mesh-free)
-        scores = sh.logical_constraint(
-            scores + ops.wnn_scores(tuples, h3, words, mask, zero_bias,
-                                    backend=backend, entries=entries),
-            ("batch", "classes"))
+        # inside a mesh each partial is ("batch", "classes")-sharded
+        # by wnn_scores' shard_map
+        scores = scores + ops.wnn_scores(tuples, h3, words, mask, zero_bias,
+                                         backend=backend, entries=entries)
     # the bias add must ALSO be pinned: bias is class-sharded, and an
     # unconstrained `scores + bias` lets GSPMD hoist the gather above the
     # add — two all-gathers of the (B, M) matrix instead of the one this

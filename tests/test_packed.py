@@ -367,7 +367,7 @@ def test_packed_infer_cell_lowers(tiny_spec):
 
 def test_uln_xl_exceeds_fused_vmem_but_fits_packed():
     """The geometry the packed subsystem exists for: ULN-XL's largest
-    submodel cannot block inside 16 MiB VMEM as an int8 one-hot, and
+    submodel cannot block inside 16 MiB VMEM as int8 tables, and
     comfortably can as uint32 bitplanes."""
     from repro.kernels import fused_wnn, packed_wnn as pk
     from repro.launch.uleen_cell import ULN_XL_SPEC
@@ -375,12 +375,9 @@ def test_uln_xl_exceeds_fused_vmem_but_fits_packed():
     sm = max(ULN_XL_SPEC.submodels, key=lambda s: s.entries)
     b, m = 256, ULN_XL_SPEC.num_classes
     n_f = ULN_XL_SPEC.num_filters(sm)
-    bb, bf = fused_wnn.resolve_blocks(b, sm.entries)
-    fused_bytes = fused_wnn.block_vmem_bytes(bb, bf, sm.inputs_per_filter,
-                                             m, sm.entries)
-    w = pk.word_count(sm.entries)
-    pbb, pbf = pk.resolve_blocks(b, w)
-    packed_bytes = pk.block_vmem_bytes(pbb, pbf, sm.inputs_per_filter, m, w)
+    n = sm.inputs_per_filter
+    fused_bytes = fused_wnn.vmem_plan(b, n, m, sm.entries)["vmem_bytes"]
+    packed_bytes = pk.vmem_plan(b, n, m, sm.entries)["vmem_bytes"]
     assert fused_bytes > vmem, (fused_bytes, n_f)
     assert packed_bytes < vmem
 

@@ -113,3 +113,16 @@ def test_host_mesh_constraint_runs():
         y = jax.jit(lambda x: sh.logical_constraint(x * 2, ("batch", "ffn")))(
             jnp.ones((4, 8)))
     np.testing.assert_array_equal(np.asarray(y), 2 * np.ones((4, 8)))
+
+
+@pytest.mark.parametrize("shape,axes", [((1,), ("data",)),
+                                        ((2, 4), ("pod", "data")),
+                                        ((2, 4), ("data", "model"))])
+def test_make_mesh_axes_are_auto(shape, axes):
+    """Every mesh the repo builds is steered by GSPMD
+    (`with_sharding_constraint`, jit shardings), which Explicit axes —
+    `jax.make_mesh`'s default — reject."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh(shape, axes)
+    assert mesh.axis_types == (AxisType.Auto,) * len(axes)
