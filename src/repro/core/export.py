@@ -10,6 +10,7 @@ from __future__ import annotations
 import dataclasses
 from typing import NamedTuple, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -319,13 +320,14 @@ def scores_from_prep(prep, bits: jnp.ndarray, *,
     m = prep.bias.shape[0]
     scores = jnp.zeros((bits.shape[0], m), jnp.int32)
     zero_bias = jnp.zeros((m,), jnp.int32)
-    for table, mask, perm, h3 in zip(prep.tables, prep.masks, prep.perms,
-                                     prep.h3s):
+    for i, (table, mask, perm, h3) in enumerate(zip(
+            prep.tables, prep.masks, prep.perms, prep.h3s)):
         tuples = bits[:, perm].astype(jnp.int8)
         # inside a mesh each partial is ("batch", "classes")-sharded
         # by wnn_scores' shard_map
-        scores = scores + ops.wnn_scores(tuples, h3, table, mask, zero_bias,
-                                         backend=backend)
+        with jax.named_scope(f"submodel{i}"):
+            scores = scores + ops.wnn_scores(tuples, h3, table, mask,
+                                             zero_bias, backend=backend)
     # pin the bias add too: bias is class-sharded, and an unconstrained
     # `scores + bias` lets GSPMD hoist the gather above the add — two
     # all-gathers instead of the dataflow's promised one

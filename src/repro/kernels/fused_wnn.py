@@ -192,6 +192,7 @@ def wnn_lookup(tuples: jnp.ndarray, params: jnp.ndarray, table: jnp.ndarray,
                                packed=packed)
     out = pl.pallas_call(
         kernel,
+        name="packed_wnn" if packed else "fused_wnn",
         grid=(bp // bb, fp // bf, m // bm),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -697,35 +697,48 @@ class WnnBatcher:
 
     def step(self) -> int:
         """Serve up to `slots` queued requests in one fixed-shape launch;
-        returns the number served (0 when idle)."""
+        returns the number served (0 when idle).
+
+        Spans, one tree per launch: `wnn.step` (attrs `batch`, the launch
+        ordinal, and `take`) over `wnn.assemble` (the batch buffer, queue
+        pops, row copies), `wnn.batch` and `wnn.deliver` (the per-row
+        results); `wnn.batch` over `wnn.transfer` (the batch to the
+        device), `wnn.dispatch` (the jitted call until it returns) and
+        `wnn.wait` (the scores back on the host)."""
         if not self.queue:
             return 0
         rec = obs_registry.get_recorder()
         take = min(self.slots, len(self.queue))
-        batch = np.zeros((self.slots, self.total_bits), np.uint8)
-        rids = []
-        for i in range(take):
-            rid, bits = self.queue.popleft()
-            batch[i] = bits
-            rids.append(rid)
-        with rec.span("wnn.batch", take=take):
-            if self.mesh is None:
-                scores = np.asarray(
-                    self._scores(self._prep, jnp.asarray(batch)))
-            else:
-                with sh.use_mesh(self.mesh, self.rules):
-                    scores = np.asarray(self._scores(
-                        self._prep,
-                        jax.device_put(batch, self._bits_sharding)))
-        t = self.clock()
-        lat_hist_global = rec.histogram("serve.wnn.latency_s")
-        for i, rid in enumerate(rids):
-            res = self.results[rid]
-            res.scores = scores[i]
-            res.pred = int(np.argmax(scores[i]))
-            res.t_done = t
-            self.lat_hist.observe(res.latency)
-            lat_hist_global.observe(res.latency)
+        with rec.span("wnn.step", batch=self.batches, take=take):
+            with rec.span("wnn.assemble"):
+                batch = np.zeros((self.slots, self.total_bits), np.uint8)
+                rids = []
+                for i in range(take):
+                    rid, bits = self.queue.popleft()
+                    batch[i] = bits
+                    rids.append(rid)
+            with rec.span("wnn.batch", take=take):
+                if self.mesh is None:
+                    with rec.span("wnn.transfer"):
+                        bits = jnp.asarray(batch)
+                    with rec.span("wnn.dispatch"):
+                        scores = self._scores(self._prep, bits)
+                else:
+                    with sh.use_mesh(self.mesh, self.rules):
+                        with rec.span("wnn.transfer"):
+                            bits = jax.device_put(batch, self._bits_sharding)
+                        with rec.span("wnn.dispatch"):
+                            scores = self._scores(self._prep, bits)
+                with rec.span("wnn.wait"):
+                    scores = np.asarray(scores)
+            with rec.span("wnn.deliver"):
+                t = self.clock()
+                for i, rid in enumerate(rids):
+                    res = self.results[rid]
+                    res.scores = scores[i]
+                    res.pred = int(np.argmax(scores[i]))
+                    res.t_done = t
+                    self.lat_hist.observe(res.latency)
         self.batches += 1
         self.served += take
         return take

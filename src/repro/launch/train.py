@@ -201,6 +201,12 @@ def train_uleen(spec, statics, bits_train, labels_train, *,
     on_step(step, params): test hook called after each optimizer step
     (the request()-based preemption drill injects there). step_delay:
     per-step sleep, widening the window the SIGTERM drill aims at.
+
+    Spans, one tree per step: `train.step` (attr `step`) over
+    `train.feed` (batch rows, their `device_put`s, the step's rng) and
+    `train.sync` (loss and accuracy back on the host). The recorder is
+    fetched per step, so one installed mid-run (from `on_step`) records
+    the steps after.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -212,12 +218,11 @@ def train_uleen(spec, statics, bits_train, labels_train, *,
     params = init_params(jax.random.PRNGKey(seed), spec, init_scale=0.1)
     opt_state = optimizer.init(params)
 
-    rec = obs_registry.get_recorder()
     rep = NamedSharding(mesh, P())
     rep_tree = lambda t: jax.tree.map(lambda _: rep, t)
     start = 0
     if ckpt_dir and restore == "auto":
-        with rec.span("ckpt.restore"):
+        with obs_registry.get_recorder().span("ckpt.restore"):
             restored, at = checkpoint.restore_latest(
                 ckpt_dir, (params, opt_state),
                 shardings=(rep_tree(params), rep_tree(opt_state)))
@@ -249,38 +254,44 @@ def train_uleen(spec, statics, bits_train, labels_train, *,
     last = start
 
     for step in range(start, steps_total):
-        idx = uleen_batch_indices(seed, step, n, global_batch)
-        bits_b = jax.device_put(bits_train[idx], dshard["bits"])
-        labels_b = jax.device_put(labels_train[idx], dshard["labels"])
-        rng = jax.random.fold_in(base_rng, step)
-        monitor.start()
-        params, opt_state, loss, acc = jit_step(
-            params, opt_state, statics_t, bits_b, labels_b, rng)
-        loss, acc = float(loss), float(acc)
-        ev = monitor.stop(step)   # observes train.step_s + EWMA gauge
-        rec.counter("train.steps").inc()
-        if step_delay:
-            time.sleep(step_delay)
-        history.append({"step": step, "loss": loss, "acc": acc})
-        last = step + 1
-        if verbose and (step % 5 == 0 or step == steps_total - 1):
-            print(f"[train] step {step}: loss={loss:.4f} acc={acc:.4f}"
-                  + (f" STRAGGLER x{ev.ratio:.1f}" if ev else ""))
-        if on_step is not None:
-            on_step(step, params)
-        want_ckpt = ckpt_dir and (step + 1) % ckpt_every == 0
-        if guard is not None and guard.preempted:
-            want_ckpt, preempted = bool(ckpt_dir), True
-        if want_ckpt:
-            with rec.span("ckpt.save", step=step + 1):
-                checkpoint.save(ckpt_dir, step + 1, (params, opt_state),
-                                keep=keep)
+        rec = obs_registry.get_recorder()
+        with rec.span("train.step", step=step):
+            with rec.span("train.feed"):
+                idx = uleen_batch_indices(seed, step, n, global_batch)
+                bits_b = jax.device_put(bits_train[idx], dshard["bits"])
+                labels_b = jax.device_put(labels_train[idx],
+                                          dshard["labels"])
+                rng = jax.random.fold_in(base_rng, step)
+            monitor.start()
+            params, opt_state, loss, acc = jit_step(
+                params, opt_state, statics_t, bits_b, labels_b, rng)
+            with rec.span("train.sync"):
+                loss, acc = float(loss), float(acc)
+            ev = monitor.stop(step)   # observes train.step_s + EWMA gauge
+            rec.counter("train.steps").inc()
+            if step_delay:
+                time.sleep(step_delay)
+            history.append({"step": step, "loss": loss, "acc": acc})
+            last = step + 1
+            if verbose and (step % 5 == 0 or step == steps_total - 1):
+                print(f"[train] step {step}: loss={loss:.4f} acc={acc:.4f}"
+                      + (f" STRAGGLER x{ev.ratio:.1f}" if ev else ""))
+            if on_step is not None:
+                on_step(step, params)
+            want_ckpt = ckpt_dir and (step + 1) % ckpt_every == 0
+            if guard is not None and guard.preempted:
+                want_ckpt, preempted = bool(ckpt_dir), True
+            if want_ckpt:
+                with obs_registry.get_recorder().span("ckpt.save",
+                                                      step=step + 1):
+                    checkpoint.save(ckpt_dir, step + 1, (params, opt_state),
+                                    keep=keep)
         if preempted:
             if verbose:
                 print(f"[train] preempted; checkpointed step {step + 1}")
             break
     if ckpt_dir and not preempted and last > start:
-        with rec.span("ckpt.save", step=last):
+        with obs_registry.get_recorder().span("ckpt.save", step=last):
             checkpoint.save(ckpt_dir, last, (params, opt_state), keep=keep)
     return {"params": params, "opt_state": opt_state, "history": history,
             "preempted": preempted, "resumed_from": start,

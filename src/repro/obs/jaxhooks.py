@@ -5,6 +5,11 @@ stay stdlib-only so numpy-only layers (`core/export.py`,
 `train/fault.py`) can record into the global recorder without pulling
 jax into their import graph.
 
+Importing it puts every span of a real `Recorder` on the profiler's host
+timeline: each `Recorder.span(name)` also opens a
+`jax.profiler.TraceAnnotation(name)`, a cheap no-op while no trace runs.
+The `NullRecorder` opens none.
+
 Three signals:
 
 * `counted(fn, counts, key)` — retrace counting. A function wrapped in
@@ -31,6 +36,8 @@ import functools
 import jax
 
 from repro.obs import registry as _registry
+
+_registry.set_annotation(jax.profiler.TraceAnnotation)
 
 
 def counted(fn, counts, key, *, prefix: str = "jax.trace", agg_key=None):

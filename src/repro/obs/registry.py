@@ -111,6 +111,9 @@ class Recorder:
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
         stack = self._stack()
+        note = _ANNOTATION(name) if _ANNOTATION is not None else None
+        if note is not None:
+            note.__enter__()
         sp = _trace.Span(
             name=name, t0=self.clock(), attrs=attrs, depth=len(stack),
             index=self._n_spans,
@@ -122,6 +125,8 @@ class Recorder:
         finally:
             stack.pop()
             sp.t1 = self.clock()
+            if note is not None:
+                note.__exit__(None, None, None)
             if len(self.spans) < self.max_spans:
                 self.spans.append(sp)
             else:
@@ -241,6 +246,20 @@ class NullRecorder:
 
 
 _RECORDER = NullRecorder()
+
+# `name -> context manager` opened around every span of a real Recorder,
+# or None. `jaxhooks` installs `jax.profiler.TraceAnnotation` here when it
+# is imported, so spans sit on the profiler's host timeline, on the clock
+# of the device ops; this module stays stdlib-only.
+_ANNOTATION = None
+
+
+def set_annotation(factory):
+    """Install the span annotation factory; returns the previous one."""
+    global _ANNOTATION
+    prev = _ANNOTATION
+    _ANNOTATION = factory
+    return prev
 
 
 def get_recorder():

@@ -50,13 +50,15 @@ def packed_scores(pt: PackedTables, bits: jnp.ndarray, *,
     bits = jnp.asarray(bits)
     scores = jnp.zeros((bits.shape[0], pt.num_classes), jnp.int32)
     zero_bias = jnp.zeros((pt.num_classes,), jnp.int32)
-    for words, mask, perm, h3, entries in zip(
-            pt.words, pt.masks, pt.perms, pt.h3s, pt.entries):
+    for i, (words, mask, perm, h3, entries) in enumerate(zip(
+            pt.words, pt.masks, pt.perms, pt.h3s, pt.entries)):
         tuples = bits[:, perm].astype(jnp.int8)          # (B, N_f, n)
         # inside a mesh each partial is ("batch", "classes")-sharded
         # by wnn_scores' shard_map
-        scores = scores + ops.wnn_scores(tuples, h3, words, mask, zero_bias,
-                                         backend=backend, entries=entries)
+        with jax.named_scope(f"submodel{i}"):
+            scores = scores + ops.wnn_scores(tuples, h3, words, mask,
+                                             zero_bias, backend=backend,
+                                             entries=entries)
     # the bias add must ALSO be pinned: bias is class-sharded, and an
     # unconstrained `scores + bias` lets GSPMD hoist the gather above the
     # add — two all-gathers of the (B, M) matrix instead of the one this

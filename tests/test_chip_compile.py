@@ -108,6 +108,7 @@ def test_packed_wnn_compiles_for_v5e(one_chip, spec):
     compiled = jax.jit(packed_wnn).lower(
         *_kernel_args(spec, one_chip, jnp.uint32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "%packed_wnn" in compiled.as_text()     # the kernel's name
     assert _fits(compiled)
 
 
@@ -118,6 +119,7 @@ def test_fused_wnn_compiles_for_v5e(one_chip, spec):
     compiled = jax.jit(fused_wnn).lower(
         *_kernel_args(spec, one_chip, jnp.int8)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "%fused_wnn" in compiled.as_text()      # the kernel's name
     assert _fits(compiled)
 
 

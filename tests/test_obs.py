@@ -13,10 +13,15 @@ Four layers, matching the package split:
   snapshot counters reconcile exactly with `stats()`, with scores still
   bit-identical to the uninstrumented oracle (spans never touch traced
   values), and a short train_uleen run exporting step-time histograms,
-  checkpoint spans, and the straggler EWMA gauge.
+  checkpoint spans, and the straggler EWMA gauge;
+* the span trees of `WnnBatcher.step` and of the `train_uleen` loop, and
+  a span's profiler annotation on a `jax.profiler` trace.
 """
+import contextlib
 import copy
+import glob
 import math
+import time
 
 import numpy as np
 import pytest
@@ -41,6 +46,38 @@ class _Clock:
 
     def __call__(self):
         return self.t
+
+
+@pytest.fixture
+def annotations():
+    """Names of the profiler annotations that spans open, in the order
+    they open: the annotation factory that `jaxhooks` installs is swapped
+    for one that notes each name."""
+    import repro.obs.jaxhooks  # noqa: F401  (installs the real factory)
+    opened = []
+
+    def note(name):
+        opened.append(name)
+        return contextlib.nullcontext()
+
+    prev = oreg.set_annotation(note)
+    yield opened
+    oreg.set_annotation(prev)
+
+
+def _children(rec, sp):
+    """`sp`'s direct children in `rec`, in the order they opened."""
+    return sorted((s for s in rec.spans if s.parent == sp.index),
+                  key=lambda s: s.index)
+
+
+def _inside(parent, children):
+    """Each child lies within its parent, and each ends before the next
+    one starts."""
+    for c in children:
+        assert parent.t0 <= c.t0 <= c.t1 <= parent.t1
+    for a, b in zip(children, children[1:]):
+        assert a.t1 <= b.t0
 
 
 # ---------------------------------------------------------------------------
@@ -257,10 +294,11 @@ def test_recording_scopes_and_restores():
     assert oreg.get_recorder() is base
 
 
-def test_disabled_recorder_emits_nothing():
+def test_disabled_recorder_emits_nothing(annotations):
     """No-op overhead pin: with observability off (the default), an
-    instrumented serve path emits zero events — the NullRecorder's
-    instruments are shared no-op singletons."""
+    instrumented serve path emits zero events and opens no profiler
+    annotation — the NullRecorder's instruments are shared no-op
+    singletons."""
     from repro.launch.scheduler import WnnBatcher
     from test_sharded_serving import _artifact, _spec
 
@@ -278,6 +316,7 @@ def test_disabled_recorder_emits_nothing():
 
     assert oreg.get_recorder() is rec
     assert rec.events_emitted == 0 and rec.spans_dropped == 0
+    assert annotations == []
     assert rec.counter("anything").value == 0
     assert rec.histogram("anything").count == 0
 
@@ -288,9 +327,103 @@ def test_disabled_recorder_emits_nothing():
     assert rec.events_emitted == 0
 
 
+def test_span_appears_in_profiler_trace(tmp_path):
+    """A span of a real Recorder is also a host event of the same name on
+    a `jax.profiler` trace, as long as the span."""
+    import jax
+    from jax.profiler import ProfileData
+
+    import repro.obs.jaxhooks  # noqa: F401  (installs the annotations)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with oreg.recording() as rec:
+            with rec.span("obs.probe") as sp:
+                time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [ev for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for ev in line.events
+              if ev.name == "obs.probe"]
+    assert len(events) == 1
+    assert abs(events[0].duration_ns * 1e-9 - sp.dur_s) < 1e-3
+
+
 # ---------------------------------------------------------------------------
 # integration: instrumented serve + train runs
 # ---------------------------------------------------------------------------
+
+def test_wnn_batcher_step_span_tree(annotations):
+    """Every non-empty `WnnBatcher.step` records one `wnn.step` span over
+    assemble, batch and deliver, the batch over transfer, dispatch and
+    wait, each child inside its parent; each span opens one profiler
+    annotation of its name."""
+    from repro.launch.scheduler import WnnBatcher
+    from test_sharded_serving import _artifact, _spec
+
+    spec = _spec(8)
+    eng = WnnBatcher(_artifact(spec, seed=11), slots=2, backend="auto")
+    rng = np.random.default_rng(0)
+    with oreg.recording() as rec:
+        for _ in range(5):
+            eng.submit(rng.integers(0, 2, (spec.total_bits,)).astype(np.uint8))
+        eng.drain()
+        assert eng.step() == 0          # an empty step records nothing
+
+    steps = [s for s in rec.spans if s.name == "wnn.step"]
+    assert [(s.attrs["batch"], s.attrs["take"]) for s in steps] == [
+        (0, 2), (1, 2), (2, 1)]
+    assert len(rec.spans) == 7 * len(steps)
+    for st in steps:
+        assert st.parent is None
+        parts = _children(rec, st)
+        assert [c.name for c in parts] == [
+            "wnn.assemble", "wnn.batch", "wnn.deliver"]
+        launch = _children(rec, parts[1])
+        assert [c.name for c in launch] == [
+            "wnn.transfer", "wnn.dispatch", "wnn.wait"]
+        _inside(st, parts)
+        _inside(parts[1], launch)
+    assert annotations == [s.name for s in
+                           sorted(rec.spans, key=lambda s: s.index)]
+
+
+def test_train_uleen_spans_from_a_recorder_installed_mid_run(annotations):
+    """`train_uleen` fetches the recorder per step: one installed from
+    `on_step` records `train.step` over `train.feed` and `train.sync` for
+    the steps after it; the steps before, under the NullRecorder, emit
+    nothing and open no annotation."""
+    from repro.launch import train as train_mod
+
+    spec, statics, bits, labels = train_mod.uleen_smoke_problem(
+        0, n_train=512)
+    base = oreg.get_recorder()
+    rec = oreg.Recorder()
+
+    def on_step(step, params):
+        if step == 1:
+            oreg.set_recorder(rec)
+
+    try:
+        train_mod.train_uleen(
+            spec, statics, bits, labels, steps_total=4, global_batch=64,
+            lr=1e-3, grad_blocks=2, seed=0, on_step=on_step, verbose=False)
+    finally:
+        oreg.set_recorder(base)
+
+    steps = [s for s in rec.spans if s.name == "train.step"]
+    assert [s.attrs["step"] for s in steps] == [2, 3]
+    assert len(rec.spans) == 3 * len(steps)
+    for st in steps:
+        parts = _children(rec, st)
+        assert [c.name for c in parts] == ["train.feed", "train.sync"]
+        _inside(st, parts)
+    assert rec.counter("train.steps").value == 2
+    assert base.events_emitted == 0
+    assert annotations == [s.name for s in
+                           sorted(rec.spans, key=lambda s: s.index)]
+
 
 def test_tenant_batcher_stress_snapshot_reconciles(tmp_path):
     """Acceptance cell: a WnnTenantBatcher stress run under `recording()`
