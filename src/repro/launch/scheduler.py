@@ -31,7 +31,7 @@ import collections
 import dataclasses
 import enum
 import time
-from typing import Any, Callable, Iterable, List, Optional
+from typing import Any, Callable, Iterable, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -606,6 +606,14 @@ class WnnResult:
         return self.t_done - self.t_submit
 
 
+class _WnnLaunch(NamedTuple):
+    """One `WnnBatcher` launch from its dispatch until it is delivered."""
+    batch: int                         # launch ordinal
+    rids: list
+    scores: jax.Array                  # (slots, M) on the device
+    bits: np.ndarray                   # host batch buffer, kept until done
+
+
 class WnnBatcher:
     """Micro-batching serve path for WNN artifact inference — the
     classification analogue of `Engine` (DESIGN §2 "Packed layout" /
@@ -629,6 +637,21 @@ class WnnBatcher:
     computes per-shard partial score columns plus the gathered (B, M)
     argmax. Still exactly one compile — the mesh changes placement, not
     shapes — and bit-identical int32 scores to the unsharded batcher.
+
+    At most one launch is in flight, the same with or without a mesh:
+    `step()` assembles, transfers and dispatches launch k, starts its
+    scores' copy to the host, and only then waits for and delivers launch
+    k-1, whose device work ran under the host work since the last step.
+    What to do with launch k is read from the queue: if rows are still
+    queued after its take it stays in flight; if the take emptied the
+    queue, the step waits for and delivers it too. So whenever `queue` is
+    empty after `step()`, nothing is in flight and every submitted row
+    has its answer; at low load, where each step takes all that is
+    queued, a step serves its own launch. A launch keeps its rids, its
+    device scores and its host batch buffer until delivered, and every
+    launch allocates a fresh buffer, so none is written while the device
+    may still read it. `stats()["overlapped"]` counts the launches whose
+    wait found their scores already computed.
 
         batcher = WnnBatcher(artifact, slots=64, backend="auto")
         rid = batcher.submit(encoded_bits_row)
@@ -679,8 +702,10 @@ class WnnBatcher:
         self.queue: collections.deque = collections.deque()
         self.results: dict = {}
         self._next_rid = 0
+        self._in_flight: Optional[_WnnLaunch] = None
         self.batches = 0
         self.served = 0
+        self.overlapped = 0
 
     def submit(self, bits) -> int:
         """Queue one encoded input (total_bits,) {0,1}; returns its rid."""
@@ -696,18 +721,25 @@ class WnnBatcher:
         return rid
 
     def step(self) -> int:
-        """Serve up to `slots` queued requests in one fixed-shape launch;
-        returns the number served (0 when idle).
+        """Launch up to `slots` queued requests in one fixed-shape call and
+        complete the launch before it (both, if the take empties the
+        queue); returns the rows launched, 0 when idle.
 
         Spans, one tree per launch: `wnn.step` (attrs `batch`, the launch
         ordinal, and `take`) over `wnn.assemble` (the batch buffer, queue
-        pops, row copies), `wnn.batch` and `wnn.deliver` (the per-row
-        results); `wnn.batch` over `wnn.transfer` (the batch to the
-        device), `wnn.dispatch` (the jitted call until it returns) and
-        `wnn.wait` (the scores back on the host)."""
-        if not self.queue:
-            return 0
+        pops, row copies), `wnn.batch` and one `wnn.deliver` per launch
+        completed (the per-row results); `wnn.batch` over `wnn.transfer`
+        (the batch to the device), `wnn.dispatch` (the jitted call until
+        it returns) and one `wnn.wait` per launch completed (its scores
+        on the host; attr `ready`, whether the device had finished when
+        the wait began). `wnn.wait` and `wnn.deliver` carry `batch`, the
+        ordinal of the launch they complete."""
         rec = obs_registry.get_recorder()
+        if not self.queue:
+            if self._in_flight is not None:    # only after a step that raised
+                launch, self._in_flight = self._in_flight, None
+                self._deliver(rec, launch, self._wait(rec, launch))
+            return 0
         take = min(self.slots, len(self.queue))
         with rec.span("wnn.step", batch=self.batches, take=take):
             with rec.span("wnn.assemble"):
@@ -729,19 +761,38 @@ class WnnBatcher:
                             bits = jax.device_put(batch, self._bits_sharding)
                         with rec.span("wnn.dispatch"):
                             scores = self._scores(self._prep, bits)
-                with rec.span("wnn.wait"):
-                    scores = np.asarray(scores)
-            with rec.span("wnn.deliver"):
-                t = self.clock()
-                for i, rid in enumerate(rids):
-                    res = self.results[rid]
-                    res.scores = scores[i]
-                    res.pred = int(np.argmax(scores[i]))
-                    res.t_done = t
-                    self.lat_hist.observe(res.latency)
+                scores.copy_to_host_async()
+                launch = _WnnLaunch(self.batches, rids, scores, batch)
+                due = [] if self._in_flight is None else [self._in_flight]
+                if self.queue:
+                    self._in_flight = launch
+                else:
+                    self._in_flight = None
+                    due.append(launch)
+                done = [(d, self._wait(rec, d)) for d in due]
+            for d, host_scores in done:
+                self._deliver(rec, d, host_scores)
         self.batches += 1
         self.served += take
         return take
+
+    def _wait(self, rec, launch: _WnnLaunch) -> np.ndarray:
+        """`launch`'s scores on the host."""
+        ready = launch.scores.is_ready()
+        self.overlapped += int(ready)
+        with rec.span("wnn.wait", batch=launch.batch, ready=ready):
+            return np.asarray(launch.scores)
+
+    def _deliver(self, rec, launch: _WnnLaunch, scores: np.ndarray):
+        """Each of `launch`'s rows gets its scores, answer and `t_done`."""
+        with rec.span("wnn.deliver", batch=launch.batch):
+            t = self.clock()
+            for i, rid in enumerate(launch.rids):
+                res = self.results[rid]
+                res.scores = scores[i]
+                res.pred = int(np.argmax(scores[i]))
+                res.t_done = t
+                self.lat_hist.observe(res.latency)
 
     def drain(self) -> List[WnnResult]:
         """Serve until the queue is empty; results in rid order."""
@@ -761,6 +812,7 @@ class WnnBatcher:
         return {"requests": len(done), "batches": self.batches,
                 "submitted": self._next_rid, "served": self.served,
                 "queued": len(self.queue),
+                "overlapped": self.overlapped,
                 "class_shards": self.class_shards,
                 "occupancy": occupancy,
                 "traces": int(self.trace_counts["batch_scores"]),

@@ -356,8 +356,11 @@ def test_span_appears_in_profiler_trace(tmp_path):
 
 def test_wnn_batcher_step_span_tree(annotations):
     """Every non-empty `WnnBatcher.step` records one `wnn.step` span over
-    assemble, batch and deliver, the batch over transfer, dispatch and
-    wait, each child inside its parent; each span opens one profiler
+    assemble, batch and one deliver per launch it completes; the batch
+    over transfer and dispatch of its own launch, then one wait per
+    launch completed. With one launch in flight, step k completes launch
+    k-1, and the step that empties the queue completes its own launch
+    too. Each child lies inside its parent; each span opens one profiler
     annotation of its name."""
     from repro.launch.scheduler import WnnBatcher
     from test_sharded_serving import _artifact, _spec
@@ -374,17 +377,23 @@ def test_wnn_batcher_step_span_tree(annotations):
     steps = [s for s in rec.spans if s.name == "wnn.step"]
     assert [(s.attrs["batch"], s.attrs["take"]) for s in steps] == [
         (0, 2), (1, 2), (2, 1)]
-    assert len(rec.spans) == 7 * len(steps)
-    for st in steps:
+    completes = [[], [0], [1, 2]]       # launches each step waits for
+    assert len(rec.spans) == sum(5 + 2 * len(c) for c in completes)
+    for st, done in zip(steps, completes):
         assert st.parent is None
         parts = _children(rec, st)
         assert [c.name for c in parts] == [
-            "wnn.assemble", "wnn.batch", "wnn.deliver"]
+            "wnn.assemble", "wnn.batch"] + ["wnn.deliver"] * len(done)
         launch = _children(rec, parts[1])
         assert [c.name for c in launch] == [
-            "wnn.transfer", "wnn.dispatch", "wnn.wait"]
+            "wnn.transfer", "wnn.dispatch"] + ["wnn.wait"] * len(done)
+        assert [c.attrs["batch"] for c in launch[2:]] == done
+        assert [c.attrs["batch"] for c in parts[2:]] == done
+        assert all(isinstance(c.attrs["ready"], bool) for c in launch[2:])
         _inside(st, parts)
         _inside(parts[1], launch)
+    waits = [s for s in rec.spans if s.name == "wnn.wait"]
+    assert eng.stats()["overlapped"] == sum(s.attrs["ready"] for s in waits)
     assert annotations == [s.name for s in
                            sorted(rec.spans, key=lambda s: s.index)]
 

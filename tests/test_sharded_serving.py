@@ -333,6 +333,84 @@ def test_wnn_batcher_interleaving_stress_sharded():
 
 
 # ---------------------------------------------------------------------------
+# WnnBatcher: at most one launch in flight
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("seed,sharded", [
+    (0, False), (1, False), (2, False),
+    pytest.param(3, True, marks=needs8)])
+def test_wnn_batcher_keeps_one_launch_in_flight(seed, sharded):
+    """A step that leaves rows queued returns its take and leaves its
+    launch in flight: the launch's rows are answered by the next step,
+    never later, and nothing else is unanswered but the queue. A step
+    that empties the queue answers every row. The answers are the
+    artifact's scores, bit for bit, with or without a mesh."""
+    from repro.launch.scheduler import WnnBatcher
+    spec = _spec(8 if sharded else 10)
+    art = _artifact(spec, seed=200 + seed)
+    rng = np.random.default_rng(seed)
+    eng = WnnBatcher(art, slots=4, backend="auto",
+                     mesh=_mesh8() if sharded else None)
+    submitted = {}
+
+    def submit(n):
+        for _ in range(n):
+            row = rng.integers(0, 2, (spec.total_bits,)).astype(np.uint8)
+            submitted[eng.submit(row)] = row
+
+    submit(11)
+    for _ in range(40):
+        if rng.random() < 0.4:
+            submit(int(rng.integers(0, 7)))
+        if not eng.queue:
+            continue
+        queued = [rid for rid, _ in eng.queue]
+        take = eng.step()
+        assert take == min(4, len(queued))
+        pending = {r.rid for r in eng.results.values() if r.t_done is None}
+        if eng.queue:
+            assert pending == set(queued)      # launch k and the queue
+        else:
+            assert pending == set()
+    eng.drain()
+
+    results = [eng.results[rid] for rid in sorted(submitted)]
+    expect = np.asarray(export.artifact_scores(
+        art, jnp.asarray(np.stack([submitted[r.rid] for r in results]))))
+    np.testing.assert_array_equal(np.stack([r.scores for r in results]),
+                                  expect)
+    assert [r.pred for r in results] == list(expect.argmax(-1))
+
+
+def test_wnn_batcher_overlapped_counts_the_ready_waits():
+    """`stats()["overlapped"]` counts the waits that found their launch
+    finished, one wait per launch; while a launch is in flight its rows
+    are served but not yet requests answered, and after the queue
+    empties `requests`, `served` and `submitted` agree."""
+    from repro.launch.scheduler import WnnBatcher
+    from repro.obs import registry as oreg
+    spec = _spec(10)
+    eng = WnnBatcher(_artifact(spec, seed=5), slots=2, backend="auto")
+    rng = np.random.default_rng(5)
+    with oreg.recording() as rec:
+        for _ in range(7):
+            eng.submit(rng.integers(0, 2, (spec.total_bits,)).astype(np.uint8))
+        assert eng.step() == 2
+        st = eng.stats()
+        assert (st["batches"], st["served"], st["requests"]) == (1, 2, 0)
+        eng._in_flight.scores.block_until_ready()   # its wait finds it ready
+        eng.drain()
+    waits = [s for s in rec.spans if s.name == "wnn.wait"]
+    st = eng.stats()
+    assert [s.attrs["batch"] for s in waits] == list(range(st["batches"]))
+    assert waits[0].attrs["ready"]
+    assert st["overlapped"] == sum(s.attrs["ready"] for s in waits) >= 1
+    assert st["batches"] == 4 and st["occupancy"] == 7 / 8
+    assert st["requests"] == st["served"] == st["submitted"] == 7
+    assert st["queued"] == 0
+
+
+# ---------------------------------------------------------------------------
 # The sharded production cell lowers with partitioned tables
 # ---------------------------------------------------------------------------
 
